@@ -23,9 +23,7 @@ from repro.engine.api import (  # noqa: F401
     ENGINE_OPTIONAL_METRIC_KEYS,
     FitReport,
     StepExecutor,
-    cost_analysis_dict,
     ensure_metric_contract,
-    mesh_context,
 )
 from repro.engine.callbacks import (  # noqa: F401
     Callback,

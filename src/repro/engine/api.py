@@ -20,7 +20,6 @@ schedules (elastic meshes, multi-host lanes, new SAM variants) are new
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from typing import Any, Optional, Protocol, runtime_checkable
 
@@ -74,28 +73,3 @@ def ensure_metric_contract(metrics: dict, *, tau, perturbed) -> dict:
     metrics.setdefault("tau", tau)
     metrics.setdefault("perturbed", perturbed)
     return metrics
-
-
-def mesh_context(mesh) -> contextlib.AbstractContextManager:
-    """Version-portable 'make `mesh` the ambient mesh' context.
-
-    jax >= 0.6 spells this `jax.set_mesh`; on older releases (this container
-    ships 0.4.37) `Mesh` itself is the context manager that scopes
-    `with_sharding_constraint(PartitionSpec(...))`.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """Version-portable `compiled.cost_analysis()`.
-
-    jax <= 0.4 returns a [per-device dict]; newer releases return the dict
-    directly. Always returns a (possibly empty) dict.
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from repro.core import Method, MethodConfig, TrainState, init_train_state, make_method
 from repro.core.api import LossFn
 from repro.core.async_sam import AsyncSamState
-from repro.engine.api import ensure_metric_contract, mesh_context
+from repro.engine.api import ensure_metric_contract
 from repro.optim import GradientTransform, configure_fused
 from repro.utils import buckets
 
@@ -135,7 +135,7 @@ class FusedExecutor:
             return contextlib.nullcontext()
         from repro.models.partitioning import activation_sharding
         stack = contextlib.ExitStack()
-        stack.enter_context(mesh_context(self.mesh))
+        stack.enter_context(jax.set_mesh(self.mesh))
         stack.enter_context(activation_sharding(self.mesh))
         return stack
 
@@ -197,6 +197,14 @@ class FusedExecutor:
             return jax.jit(self._step_raw, in_shardings=(state_sh, batch_sh),
                            out_shardings=(state_sh, None),
                            donate_argnums=donate).lower(state_sds, batch_sds)
+
+    def compile_step(self, state: TrainState, batch: dict):
+        """Compile the step ahead of time exactly as `step` will run it (the
+        same jit, the same arguments) and return the compiled program, for
+        set-up timing and inspection; the first `step` reuses this compile."""
+        assert self._jitted is not None, "call init_state before compile_step"
+        with self._scope():
+            return self._jitted.lower(state, batch).compile()
 
     def resize(self, state: TrainState, new_mesh) -> TrainState:
         """Elastic re-entry: re-place the live `state` onto `new_mesh` and

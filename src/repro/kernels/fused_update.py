@@ -20,7 +20,9 @@ Scalar operands (clip scale, lr, bias corrections) enter through SMEM;
 static hyperparameters (momentum, betas, weight decay) are baked into the
 kernel. All accumulation is fp32 regardless of operand dtype; mixed-dtype
 operand pairs (bf16 params + fp32 gradient/state buckets) are supported.
-Chunks follow kernels.sam_perturb: (8,128)-lane-aligned 1-D blocks, padded.
+Chunks follow kernels.sam_perturb: (8,128)-lane-aligned 1-D blocks, padded;
+the reductions view each chunk as (512, 128) lane rows and emit one (8, 128)
+tile of partials per chunk, finished outside the kernel.
 The jnp oracles live in kernels.ref (tests/test_kernels.py sweeps both).
 """
 from __future__ import annotations
@@ -32,10 +34,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.sam_perturb import CHUNK, _pad_flat
+from repro.kernels.sam_perturb import (CHUNK, ROW_BLOCK, TILE_BLOCK, _pad_flat,
+                                       as_rows, fold_tile, partials_shape)
 
 _VEC = pl.BlockSpec((CHUNK,), lambda i: (i,))
-_PART = pl.BlockSpec((1,), lambda i: (i,))
 _SCAL = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
@@ -76,27 +78,27 @@ def fused_axpy(alpha, x_flat: jax.Array, y_flat: jax.Array, *,
 def _dot_norms_kernel(a_ref, b_ref, dot_ref, aa_ref, bb_ref):
     a = _f32(a_ref)
     b = _f32(b_ref)
-    dot_ref[0] = jnp.sum(a * b)
-    aa_ref[0] = jnp.sum(a * a)
-    bb_ref[0] = jnp.sum(b * b)
+    dot_ref[...] = fold_tile(a * b)
+    aa_ref[...] = fold_tile(a * a)
+    bb_ref[...] = fold_tile(b * b)
 
 
 def fused_dot_norms(a_flat: jax.Array, b_flat: jax.Array, *,
                     interpret: bool = False
                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """(<a,b>, ||a||^2, ||b||^2) with fp32 chunk partials summed outside."""
+    """(<a,b>, ||a||^2, ||b||^2) with fp32 tile partials summed outside."""
     a, _ = _pad_flat(a_flat)
     b, _ = _pad_flat(b_flat)
     n_chunks = a.shape[0] // CHUNK
-    part = jax.ShapeDtypeStruct((n_chunks,), jnp.float32)
+    part = partials_shape(n_chunks)
     dot, aa, bb = pl.pallas_call(
         _dot_norms_kernel,
         grid=(n_chunks,),
-        in_specs=[_VEC, _VEC],
-        out_specs=[_PART, _PART, _PART],
+        in_specs=[ROW_BLOCK, ROW_BLOCK],
+        out_specs=[TILE_BLOCK, TILE_BLOCK, TILE_BLOCK],
         out_shape=[part, part, part],
         interpret=interpret,
-    )(a, b)
+    )(as_rows(a), as_rows(b))
     return jnp.sum(dot), jnp.sum(aa), jnp.sum(bb)
 
 
@@ -106,12 +108,12 @@ def fused_dot_norms(a_flat: jax.Array, b_flat: jax.Array, *,
 
 def _delta_amax_kernel(p_ref, s_ref, e_ref, out_ref):
     d = _f32(p_ref) - _f32(s_ref) + _f32(e_ref)
-    out_ref[0] = jnp.max(jnp.abs(d))
+    out_ref[...] = fold_tile(jnp.abs(d), jnp.max)
 
 
 def delta_amax(p_flat: jax.Array, s_flat: jax.Array, e_flat: jax.Array, *,
                interpret: bool = False) -> jax.Array:
-    """max |p - s + e| (fp32 chunk partials, final max outside).
+    """max |p - s + e| (fp32 tile partials, final max outside).
 
     The scale probe for the int8 JOB-delta encoding: one read pass over the
     params bucket, its shadow, and the error-feedback residual.
@@ -123,11 +125,11 @@ def delta_amax(p_flat: jax.Array, s_flat: jax.Array, e_flat: jax.Array, *,
     partials = pl.pallas_call(
         _delta_amax_kernel,
         grid=(n_chunks,),
-        in_specs=[_VEC, _VEC, _VEC],
-        out_specs=_PART,
-        out_shape=jax.ShapeDtypeStruct((n_chunks,), jnp.float32),
+        in_specs=[ROW_BLOCK, ROW_BLOCK, ROW_BLOCK],
+        out_specs=TILE_BLOCK,
+        out_shape=partials_shape(n_chunks),
         interpret=interpret,
-    )(p, s, e)
+    )(as_rows(p), as_rows(s), as_rows(e))
     return jnp.max(partials)
 
 

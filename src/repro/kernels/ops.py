@@ -5,17 +5,25 @@ Models call these entry points only; the backend choice is per-call overridable
 FLOP-equivalent jnp paths everywhere else (including the 512-fake-device CPU
 dry-run, which cannot lower TPU Pallas). `interpret=True` Pallas execution is
 reserved for the correctness tests.
+
+The sequence kernels (flash attention, the mamba2 and rwkv6 scans) have no
+backward kernels yet: their Pallas forwards are differentiated through the
+VJP of the matching `ref` function, recomputed from the saved inputs. XLA
+cannot partition a Mosaic kernel, so under a multi-device mesh they run per
+device in a shard_map.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import logging
+from typing import Callable, Optional
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 
+log = logging.getLogger("repro.kernels")
 _FORCED_IMPL: Optional[str] = None  # test hook: "jnp" | "pallas" | "pallas_interpret"
 
 
@@ -33,6 +41,61 @@ def _resolve(impl: Optional[str]) -> str:
     return "pallas" if platform == "tpu" else "jnp"
 
 
+def _with_ref_vjp(kernel: Callable, reference: Callable) -> Callable:
+    """`kernel` forward; backward is `reference`'s VJP at the same inputs."""
+    @jax.custom_vjp
+    def f(*args):
+        return kernel(*args)
+
+    def fwd(*args):
+        return kernel(*args), args
+
+    def bwd(args, ct):
+        return jax.vjp(reference, *args)[1](ct)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def _sequence_kernel(kernel: Callable, reference: Callable, args: tuple,
+                     in_dims: tuple, out_dims):
+    """Pallas `kernel` with `reference`'s VJP, per device under a mesh.
+
+    `in_dims`/`out_dims` name each array dim with a logical axis of
+    `models.partitioning` ("batch", "model") or None; `out_dims` is a list
+    for several outputs. Under a multi-device ambient mesh each logical axis
+    splits over its mesh axes where every dim of that name divides them, and
+    is otherwise replicated (each device then repeats that work; logged).
+    """
+    from repro.models.partitioning import fit_axes
+
+    f = _with_ref_vjp(kernel, reference)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return f(*args)
+    use = {}
+    for name in {d for dims in in_dims for d in dims if d is not None}:
+        sizes = [x.shape[i] for x, dims in zip(args, in_dims)
+                 for i, d in enumerate(dims) if d == name]
+        use[name] = fit_axes(mesh, name, sizes)
+        if use[name] is None:
+            log.warning("%s: %r dims %s do not split over mesh %s; replicated",
+                        getattr(kernel, "func", kernel).__name__, name, sizes,
+                        dict(mesh.shape))
+
+    def spec(dims):
+        return P(*(use.get(d) for d in dims))
+
+    out_specs = (tuple(map(spec, out_dims)) if isinstance(out_dims, list)
+                 else spec(out_dims))
+    return jax.shard_map(f, mesh=mesh, in_specs=tuple(map(spec, in_dims)),
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+_B, _H = "batch", "model"    # heads split over the tensor-parallel axis
+_BSHD = (_B, None, _H, None)   # (B, S, H, d) activations
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     impl: Optional[str] = None) -> jax.Array:
@@ -41,8 +104,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if mode == "jnp":
         return ref.flash_attention_jnp(q, k, v, causal=causal, window=window)
     from repro.kernels import flash_attention as fa
-    return fa.flash_attention(q, k, v, causal=causal, window=window,
-                              interpret=(mode == "pallas_interpret"))
+    return _sequence_kernel(
+        functools.partial(fa.flash_attention, causal=causal, window=window,
+                          interpret=(mode == "pallas_interpret")),
+        functools.partial(ref.flash_attention_jnp, causal=causal,
+                          window=window),
+        (q, k, v), (_BSHD,) * 3, _BSHD)
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -63,13 +130,19 @@ def mamba2_mix(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                impl: Optional[str] = None) -> tuple[jax.Array, jax.Array]:
     """Mamba2/SSD sequence mixing. Returns (y, final_state)."""
     mode = _resolve(impl)
-    if mode == "jnp":
+    if mode == "jnp" or init_state is not None:   # the kernel starts at zero
         return ref.mamba2_chunked_jnp(x, dt, a, b, c, d, chunk=chunk,
                                       init_state=init_state)
     from repro.kernels import mamba2_scan as m2
-    return m2.mamba2_chunked(x, dt, a, b, c, d, chunk=chunk,
-                             init_state=init_state,
-                             interpret=(mode == "pallas_interpret"))
+    # one group (the common case) is shared by every head: replicate it
+    groups = (_B, None, _H if b.shape[2] > 1 else None, None)
+    return _sequence_kernel(
+        functools.partial(m2.mamba2_chunked, chunk=chunk,
+                          interpret=(mode == "pallas_interpret")),
+        functools.partial(ref.mamba2_chunked_jnp, chunk=chunk),
+        (x, dt, a, b, c, d),
+        (_BSHD, (_B, None, _H), (_H,), groups, groups, (_H,)),
+        [_BSHD, (_B, _H, None, None)])
 
 
 def mamba2_decode_step(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
@@ -85,11 +158,14 @@ def rwkv6_mix(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
               impl: Optional[str] = None) -> tuple[jax.Array, jax.Array]:
     """RWKV6 wkv recurrence. Returns (y, final_state)."""
     mode = _resolve(impl)
-    if mode == "jnp":
+    if mode == "jnp" or init_state is not None:   # the kernel starts at zero
         return ref.rwkv6_scan_ref(r, k, v, w, u, init_state=init_state)
     from repro.kernels import rwkv6_scan as r6
-    return r6.rwkv6_chunked(r, k, v, w, u, init_state=init_state,
-                            interpret=(mode == "pallas_interpret"))
+    return _sequence_kernel(
+        functools.partial(r6.rwkv6_chunked,
+                          interpret=(mode == "pallas_interpret")),
+        ref.rwkv6_scan_ref, (r, k, v, w, u),
+        (_BSHD,) * 4 + ((_H, None),), [_BSHD, (_B, _H, None, None)])
 
 
 def sam_perturb(w_flat: jax.Array, g_flat: jax.Array, rho, sq_norm, *,

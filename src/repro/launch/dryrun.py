@@ -194,12 +194,11 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     # default execution profile: AsyncSAM with b'/b=25% and 4 microbatches
     mcfg = method_cfg or MethodConfig(name=method, n_microbatches=4)
 
-    from repro.engine import mesh_context
     from repro.models.partitioning import activation_sharding
 
     t0 = time.time()
     try:
-        with mesh_context(mesh), activation_sharding(mesh):
+        with jax.set_mesh(mesh), activation_sharding(mesh):
             if shape.kind == "train":
                 # the Engine's executor owns the jit/sharding plumbing here
                 # (the same path launch/train.py drives), not a local shim
@@ -248,8 +247,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             result.compile_s = time.time() - t1
 
             mem = compiled.memory_analysis()
-            from repro.engine import cost_analysis_dict
-            cost = cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis() or {}
             result.flops = float(cost.get("flops", 0.0))
             result.bytes_accessed = float(cost.get("bytes accessed", 0.0))
             if mem is not None:

@@ -57,6 +57,7 @@ from repro.data import PipelineConfig, TokenPipeline
 from repro.engine import (CheckpointCallback, Engine, FusedExecutor,
                           HeteroExecutor, LoggingCallback, RemoteExecutor,
                           StalenessTelemetry, ThroughputMeter)
+from repro.launch.compile_cache import use_checkout_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.optim import cosine_schedule, make_optimizer
@@ -268,6 +269,7 @@ def main() -> None:
             ap.error("crash-kind chaos events recover via checkpoint-restart "
                      "— add --ckpt-dir")
 
+    use_checkout_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     bundle = build_model(cfg)
     mcfg = MethodConfig(name=args.method, rho=args.rho,

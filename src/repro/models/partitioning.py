@@ -52,6 +52,15 @@ def make_rules(mesh) -> dict:
     }
 
 
+def fit_axes(mesh, logical: str, dim_sizes: Sequence[int]
+             ) -> Optional[tuple[str, ...]]:
+    """Mesh axes of `logical` on `mesh` if they split every one of
+    `dim_sizes` evenly, else None (replicated) — `constrain`'s rule, for code
+    that places arrays itself (the per-device Pallas kernels)."""
+    axes, n = make_rules(mesh)[logical]
+    return axes if all(s % n == 0 for s in dim_sizes) else None
+
+
 @contextlib.contextmanager
 def activation_sharding(mesh):
     """Enable logical-axis constraints for code traced inside this context."""

@@ -220,12 +220,17 @@ def spawn_server(loss_spec: str, *, bind: str = "127.0.0.1:0",
     server can never block on a full pipe; the last lines are retained on the
     handle for post-mortems (including the shutdown stats line). Pool knobs
     at their zero/None defaults are left to the server's own defaults.
+
+    The child runs on the CPU unless `device` names another platform: an
+    accelerator belongs to one process, and the parent usually holds it.
     """
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env = dict(os.environ)
     env["PYTHONPATH"] = src_root + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    if device.partition(":")[0] in ("", "cpu"):
+        env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "repro.service.ascent_server",
            "--bind", bind, "--loss", loss_spec]
     if device:

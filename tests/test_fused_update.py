@@ -536,3 +536,31 @@ def test_bucketed_primitives_accept_threaded_layout_and_resident_operands():
     assert buckets.is_bucketed(out)
     _allclose_trees(out.to_tree(),
                     jax.tree.map(lambda x, y: 0.5 * y + x, a, b), **F32_TOL)
+
+
+def test_chip_kernel_selection_trains_on_cpu(monkeypatch):
+    """Rehearsal of what a TPU selects: the fused bucket-resident Form A step
+    with every kernel a Pallas kernel (interpret mode here), AdamW with a
+    clip, on a transformer whose sequence fills whole attention blocks.
+    Differentiating through the attention kernel is what used to fail."""
+    from repro.configs import get_config
+    from repro.data import PipelineConfig, TokenPipeline
+    from repro.kernels import ops
+    from repro.models import build_model
+
+    monkeypatch.setattr(ops, "_FORCED_IMPL", "pallas_interpret")
+    cfg = get_config("olmo-1b", reduced=True)
+    bundle = build_model(cfg)
+    mcfg = MethodConfig(name="async_sam", rho=0.05, ascent_fraction=0.25)
+    pipe = TokenPipeline(cfg, PipelineConfig(
+        global_batch=2, seq_len=128, ascent_fraction=0.25, prefetch=0))
+    with FusedExecutor(bundle.loss_fn, mcfg, optim.adamw(1e-3, clip_norm=1.0),
+                       fused_update=True, resident=True) as ex:
+        assert ex.fused_update and ex.resident
+        state = ex.init_state(bundle.init(KEY), jax.random.PRNGKey(1))
+        report = Engine(ex, pipe).fit(state, 2)
+    hist = report.metrics_history
+    assert report.steps_done == 2
+    assert all(np.isfinite(h["loss"]) for h in hist)
+    assert all(h["grad_norm"] > 0 for h in hist)
+    assert [h["perturbed"] for h in hist] == [0.0, 1.0]

@@ -167,6 +167,7 @@ def test_restart_budget_exhaustion_raises(tmp_path):
 def test_elastic_reshard_roundtrip(subprocess_py):
     out = subprocess_py("""
         import jax, jax.numpy as jnp
+        AUTO = (jax.sharding.AxisType.Auto,) * 2
         from repro.configs import get_config
         from repro.models import build_model
         from repro.runtime import reshard_state
@@ -180,8 +181,8 @@ def test_elastic_reshard_roundtrip(subprocess_py):
         opt = optim.adamw(1e-3)
         state = init_train_state(params, opt, method, jax.random.PRNGKey(1))
 
-        mesh_a = jax.make_mesh((4, 2), ('data', 'model'))
-        mesh_b = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh_a = jax.make_mesh((4, 2), ('data', 'model'), axis_types=AUTO)
+        mesh_b = jax.make_mesh((2, 4), ('data', 'model'), axis_types=AUTO)
         on_a = reshard_state(state, cfg, mesh_a)
         on_b = reshard_state(on_a, cfg, mesh_b)
         back = jax.device_get(on_b)

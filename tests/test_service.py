@@ -6,6 +6,7 @@ The subprocess tests spawn the real ``python -m repro.service.ascent_server``
 explicit deadline so a wedged socket fails the test instead of hanging
 tier-1 (`scripts/tier1.sh --service` adds a process-level timeout on top).
 """
+import io
 import itertools
 import time
 
@@ -709,3 +710,31 @@ def test_spawn_server_bad_loss_spec_fails_fast():
     with pytest.raises(RuntimeError, match="failed to start"):
         spawn_server("repro.service.testing:does_not_exist",
                      startup_timeout_s=60.0)
+
+
+@pytest.mark.parametrize("device,platforms", [
+    ("", "cpu"), ("cpu:0", "cpu"), ("tpu:1", None)])
+def test_spawn_server_stays_off_the_accelerator(monkeypatch, device,
+                                                platforms):
+    """A loopback server would fight its parent for the chip: the child is
+    held to the CPU unless `device` names another platform."""
+    import repro.service.ascent_server as srv
+    seen = {}
+
+    class _Exited:
+        def __init__(self, cmd, env, **kw):
+            seen["env"] = env
+            self.stdout = io.StringIO("")
+
+        def poll(self):
+            return 1
+
+        def kill(self):
+            pass
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(srv.subprocess, "Popen", _Exited)
+    with pytest.raises(RuntimeError, match="failed to start"):
+        spawn_server("repro.service.testing:mlp_loss", device=device,
+                     startup_timeout_s=5.0)
+    assert seen["env"].get("JAX_PLATFORMS") == platforms

@@ -6,9 +6,10 @@ def test_param_rules_basics(subprocess_py):
     out = subprocess_py("""
         import jax
         from jax.sharding import PartitionSpec as P
+        AUTO = (jax.sharding.AxisType.Auto,) * 2
         from repro.models.partitioning import make_rules, param_partition_spec
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = jax.make_mesh((4, 2), ('data', 'model'), axis_types=AUTO)
         rules = make_rules(mesh)
         # generic matmul weight: in->dp, out->model
         assert param_partition_spec('blocks/attn/wq', (8, 64, 64), rules) == \\
@@ -39,6 +40,7 @@ def test_mini_dryrun_train_and_decode(subprocess_py):
     """Full dry-run machinery on an 8-device host mesh with a reduced arch."""
     out = subprocess_py("""
         import dataclasses, jax
+        AUTO = (jax.sharding.AxisType.Auto,) * 2
         from repro.configs import get_config
         from repro.core import MethodConfig
         from repro.launch.sharding import (batch_spec_tree, cache_spec_tree,
@@ -47,14 +49,13 @@ def test_mini_dryrun_train_and_decode(subprocess_py):
         from repro.models import build_model, batch_spec, decode_batch_spec
         from repro.models.config import ShapeSpec
         from repro.models.partitioning import activation_sharding
-        from repro.engine import mesh_context
 
         cfg = get_config('olmo-1b', reduced=True)
         bundle = build_model(cfg)
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = jax.make_mesh((4, 2), ('data', 'model'), axis_types=AUTO)
         shape = ShapeSpec('mini_train', 'train', 64, 8)
 
-        with mesh_context(mesh), activation_sharding(mesh):
+        with jax.set_mesh(mesh), activation_sharding(mesh):
             setup = make_train_setup(bundle, MethodConfig(n_microbatches=2))
             state_sds = jax.eval_shape(lambda: setup.init_state(
                 bundle.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1)))
@@ -64,8 +65,7 @@ def test_mini_dryrun_train_and_decode(subprocess_py):
             c = jax.jit(setup.step_fn, in_shardings=(state_sh, batch_sh),
                         out_shardings=(state_sh, None), donate_argnums=(0,)
                         ).lower(state_sds, batch_sds).compile()
-            from repro.engine import cost_analysis_dict
-            assert cost_analysis_dict(c)['flops'] > 0
+            assert c.cost_analysis()['flops'] > 0
             print('TRAIN_COMPILED', int(c.memory_analysis().temp_size_in_bytes > 0))
 
             dshape = ShapeSpec('mini_decode', 'decode', 64, 8)
@@ -90,13 +90,13 @@ def test_sharded_training_matches_single_device(subprocess_py):
     (up to float summation order) on the same data."""
     out = subprocess_py("""
         import jax, jax.numpy as jnp
+        AUTO = (jax.sharding.AxisType.Auto,) * 2
         from repro.configs import get_config
         from repro.core import MethodConfig, make_method, init_train_state
         from repro import optim
         from repro.models import build_model, synth_batch
         from repro.launch.sharding import state_spec_tree, to_named
         from repro.models.partitioning import activation_sharding
-        from repro.engine import mesh_context
 
         cfg = get_config('olmo-1b', reduced=True)
         bundle = build_model(cfg)
@@ -111,8 +111,8 @@ def test_sharded_training_matches_single_device(subprocess_py):
             state = init_train_state(params, opt, method, jax.random.PRNGKey(1))
             step = method.make_step(bundle.loss_fn, opt)
             if sharded:
-                mesh = jax.make_mesh((4, 2), ('data', 'model'))
-                with mesh_context(mesh), activation_sharding(mesh):
+                mesh = jax.make_mesh((4, 2), ('data', 'model'), axis_types=AUTO)
+                with jax.set_mesh(mesh), activation_sharding(mesh):
                     sh = to_named(state_spec_tree(
                         jax.eval_shape(lambda: state), cfg, mesh), mesh)
                     state = jax.device_put(state, sh)
