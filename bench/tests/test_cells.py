@@ -1,0 +1,169 @@
+"""Each cell rehearsed on the CPU at OLMo's REDUCED sizes through the whole
+run (`run.run_cell`, past the command's refusal of a CPU), and the check
+shown to fail: with the control in the program's place, and with each fault
+a one-chip training cell can have planted in the timed path."""
+from __future__ import annotations
+
+import functools
+import json
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bench import calibrate, load, peaks, reference, run, system, weights
+
+BENCH = load.benchmark()
+ONE_CHIP = [w["name"] for w in BENCH["workloads"] if w["chips"] == 1]
+# src/repro/configs/olmo_1b.py REDUCED, at a short sequence
+REDUCED = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
+           "d_ff": 128, "vocab_size": 256, "remat": "none",
+           "compute_dtype": "float32"}
+SEED = 2**33 + 17     # a seed above 32 bits
+
+
+def tiny(name: str, **over) -> tuple[dict, dict]:
+    cell = {**load.workload(name), "seq": 64, **over}
+    config = load.config(cell["config"])
+    return cell, {**config, "model": {**config["model"], **REDUCED}}
+
+
+def run_tiny(name, *, trace=False, system_cls=None, seed=SEED, **over):
+    cell, config = tiny(name, **over)
+    return run.run_cell(
+        cell, config, seed, 0.5, trace, t_start=time.perf_counter(),
+        devices=jax.devices()[:cell["chips"]],
+        peaks=peaks.peaks("TPU v5 lite"), system_cls=system_cls)
+
+
+@pytest.mark.parametrize("name", ONE_CHIP)
+def test_rehearsal_end_to_end(name):
+    out = run_tiny(name)
+    res = out["result"]
+    assert res["correct"], out["lines"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    want = {m["name"] for m in load.metrics_for(BENCH, "end_to_end", name)}
+    assert set(res["metrics"]) == want
+    assert all(v["value"] > 0 for v in res["metrics"].values())
+    assert list(res)[-1] == "checks"
+
+
+def test_rehearsal_traced():
+    name = ONE_CHIP[0]
+    res = run_tiny(name, trace=True)["result"]
+    assert res["correct"]
+    assert res["device"]["busy_s"] > 0 and res["device"]["window_s"] > 0
+    # the CPU runs no Pallas kernel and reports no memory peak: those
+    # readers find nothing and their metrics are left out
+    assert {"loop_host_ms", "data_wait_ms", "idle_share", "mfu"} <= set(
+        res["metrics"])
+    assert "attn_fwd_roofline" not in res["metrics"]
+    assert len(res["breakdown"]["device_ops"]) <= 10
+    assert len(res["breakdown"]["idle_gaps"]) <= 10
+
+
+def test_rehearsal_on_four_virtual_devices():
+    """The harness's sharded path: the AsyncSAM cell on a (4, 1) data mesh
+    of virtual CPU devices, 8 rows and b' = 4 (one a device), per-leaf
+    state sharded by the program's rules, checked against the reference."""
+    code = f"""
+import json, sys, time
+sys.path[:0] = [{str(load.ROOT)!r}, {str(load.ROOT / 'src')!r}]
+import jax
+from bench.tests.test_cells import run_tiny
+out = run_tiny("olmo-1b-3l.async_sam", chips=4, mesh=[4, 1], batch=8)
+print(json.dumps({{"correct": out["result"]["correct"],
+                   "lines": out["lines"], "devices": jax.device_count()}}))
+"""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got["devices"] == 4
+    assert got["correct"], got["lines"]
+
+
+def test_the_command_refuses_a_cpu():
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    proc = subprocess.run(
+        [sys.executable, str(load.BENCH / "run.py"), "--workload",
+         ONE_CHIP[0], "--seed", "1", "--seconds", "1", "--trace", "0"],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 2
+    assert proc.stdout.strip() == ""
+    assert "no TPU" in proc.stderr
+
+
+# --- the check fails -----------------------------------------------------
+
+class StateUnchanged(system.ProgramSystem):
+    """Every step returns the state it was given: parameters, optimizer and
+    method state; only its step counter and key advance (else the loop
+    would never reach its step count)."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        inner = self.executor.inner
+
+        def step(state, batch):
+            new, metrics = inner.step(jax.tree.map(jnp.copy, state), batch)
+            return state._replace(step=new.step, rng=new.rng), metrics
+
+        self.executor.inner = type("Stuck", (), {
+            "step": staticmethod(step), "close": inner.close})()
+
+
+def _alter_one_token(batch):
+    tokens = np.array(batch["tokens"])
+    tokens[0, 5] = (tokens[0, 5] + 1) % 256
+    return {**batch, "tokens": jnp.asarray(tokens)}
+
+
+class Control(system.ProgramSystem):
+    """The reference at float8 put in the program's place: its three steps
+    on the batches the feed gave are what set-up reports."""
+
+    def set_up(self):
+        r = super().set_up()
+        dims = system.model_dims(self.config)
+        train = {**self.cell["train"], "method": self.cell["method"]}
+        st = reference.init_state(weights.make_params(self.seed, dims))
+        step = jax.jit(reference.make_step(dims, train, "fp8"))
+        losses, g1 = [], []
+        for k, batch in enumerate(r["batches"]):
+            st, loss, g = step(st, jax.tree.map(jnp.asarray, batch))
+            losses.append(float(loss))
+            if k == 0:
+                g1 = [float(jnp.linalg.norm(x)) for x in jax.tree.leaves(g)]
+        return {**r, "loss": losses, "g1": g1,
+                "params3": jax.tree.leaves(jax.device_get(st.params))}
+
+
+FAULTS = {
+    "control_fp8": Control,
+    "state_unchanged": StateUnchanged,
+    "half_batch": functools.partial(system.ProgramSystem,
+                                    alter_step=calibrate.half_batch),
+    "token_altered": functools.partial(system.ProgramSystem,
+                                       alter_feed=_alter_one_token),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+@pytest.mark.parametrize("name", ONE_CHIP)
+def test_a_broken_timed_path_is_not_correct(name, fault):
+    out = run_tiny(name, system_cls=FAULTS[fault])
+    assert not out["result"]["correct"], out["lines"]
+    failed = {k for k, v in out["result"]["checks"].items()
+              if not v["value"] <= v["limit"]}
+    if fault == "token_altered":
+        assert "feed_rows" in failed
+    if fault == "state_unchanged":
+        assert {"grad_gap", "change_gap"} <= failed
