@@ -134,34 +134,35 @@ def _finish(state: TrainState, optimizer: GradientTransform, grads: Pytree,
     `update_skipped` (1.0 on a skip) and `nonfinite_count` (non-finite
     gradient elements) for the host-side guard ladder (runtime.guard).
     """
-    metrics = dict(metrics)
-    fused = fused_apply(optimizer, grads, state.opt_state, state.params)
-    if fused is not None:
-        params, opt_state, gnorm = fused
-        metrics.setdefault("grad_norm", gnorm)
-    else:
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = apply_updates(state.params, updates)
-        metrics.setdefault("grad_norm", trees.global_norm(grads))
-    if guard:
-        # a single non-finite element makes the global norm non-finite, so
-        # the ok verdict needs no extra pass; the element count is one more
-        # reduction over grads, paid only when the guard is on
-        ok = (jnp.isfinite(metrics["grad_norm"])
-              & jnp.isfinite(metrics.get("loss", jnp.float32(0.0))))
-        keep = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
-        params = jax.tree.map(keep, params, state.params)
-        opt_state = jax.tree.map(keep, opt_state, state.opt_state)
-        method_state = jax.tree.map(keep, method_state, state.method_state)
-        nonfinite = sum(jnp.sum(~jnp.isfinite(g)).astype(jnp.int32)
-                        for g in jax.tree.leaves(grads))
-        metrics["update_skipped"] = (~ok).astype(jnp.float32)
-        metrics["nonfinite_count"] = jnp.asarray(nonfinite, jnp.float32)
-    rng, _ = jax.random.split(state.rng)
-    new_state = TrainState(step=state.step + 1, rng=rng, params=params,
-                           opt_state=opt_state, method_state=method_state)
-    return new_state, metrics
+    with jax.named_scope("update"):
+        metrics = dict(metrics)
+        fused = fused_apply(optimizer, grads, state.opt_state, state.params)
+        if fused is not None:
+            params, opt_state, gnorm = fused
+            metrics.setdefault("grad_norm", gnorm)
+        else:
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = apply_updates(state.params, updates)
+            metrics.setdefault("grad_norm", trees.global_norm(grads))
+        if guard:
+            # a single non-finite element makes the global norm non-finite, so
+            # the ok verdict needs no extra pass; the element count is one more
+            # reduction over grads, paid only when the guard is on
+            ok = (jnp.isfinite(metrics["grad_norm"])
+                  & jnp.isfinite(metrics.get("loss", jnp.float32(0.0))))
+            keep = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
+            params = jax.tree.map(keep, params, state.params)
+            opt_state = jax.tree.map(keep, opt_state, state.opt_state)
+            method_state = jax.tree.map(keep, method_state, state.method_state)
+            nonfinite = sum(jnp.sum(~jnp.isfinite(g)).astype(jnp.int32)
+                            for g in jax.tree.leaves(grads))
+            metrics["update_skipped"] = (~ok).astype(jnp.float32)
+            metrics["nonfinite_count"] = jnp.asarray(nonfinite, jnp.float32)
+        rng, _ = jax.random.split(state.rng)
+        new_state = TrainState(step=state.step + 1, rng=rng, params=params,
+                               opt_state=opt_state, method_state=method_state)
+        return new_state, metrics
 
 
 def step_rng(state: TrainState) -> jax.Array:

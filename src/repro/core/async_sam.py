@@ -76,88 +76,101 @@ def make_async_sam(cfg: MethodConfig) -> Method:
             # --- perturb with the STALE gradient a_{t-1} (Algorithm 1, line 5).
             # At t=0 no ascent gradient exists: rho_eff=0 degrades to SGD
             # (Algorithm 1, line 8) without a traced branch.
-            rho_eff = jnp.where(ms.have_ascent, cfg.rho, 0.0)
-            w_hat = _perturb(state.params, ms.ascent_grad, rho_eff,
-                              grad_norm=ms.ascent_norm,
-                              fused=cfg.fused_update)
+            with jax.named_scope("perturb"):
+                rho_eff = jnp.where(ms.have_ascent, cfg.rho, 0.0)
+                w_hat = _perturb(state.params, ms.ascent_grad, rho_eff,
+                                 grad_norm=ms.ascent_norm,
+                                 fused=cfg.fused_update)
 
             # --- descent gradient at the perturbed point (line 6).
-            (loss, aux), grads = vg(w_hat, batch, rng_d)
+            with jax.named_scope("descent"):
+                (loss, aux), grads = vg(w_hat, batch, rng_d)
 
             # --- NEXT ascent gradient at the *unperturbed* current params
             # (line 3; independent of the descent computation above).
             # ascent_interval > 1 (beyond-paper "AsyncSAM-k") refreshes only
             # every k-th step: average extra compute drops to f/k while tau
             # grows to at most k — EXPERIMENTS §Perf validates the accuracy.
-            if cfg.ascent_interval <= 1:
-                (loss_asc, _), a_new = vg(state.params, ascent_batch, rng_a)
-                staleness = jnp.ones((), jnp.int32)
-                reused = jnp.zeros((), jnp.float32)
-            else:
-                def fresh(_):
-                    (la, _), a = vg(state.params, ascent_batch, rng_a)
-                    return trees.tree_cast(a, jnp.float32), la, jnp.int32(1)
+            # the b' value-and-grad and the ascent-state refresh
+            with jax.named_scope("ascent"):
+                if cfg.ascent_interval <= 1:
+                    (loss_asc, _), a_new = vg(state.params, ascent_batch,
+                                              rng_a)
+                    staleness = jnp.ones((), jnp.int32)
+                    reused = jnp.zeros((), jnp.float32)
+                else:
+                    def fresh(_):
+                        (la, _), a = vg(state.params, ascent_batch, rng_a)
+                        return (trees.tree_cast(a, jnp.float32), la,
+                                jnp.int32(1))
 
-                def reuse(_):
-                    # ascent_loss is a NaN SENTINEL here (no ascent pass ran,
-                    # there is no loss to report); the explicit ascent_reused
-                    # flag below is what disambiguates it from a genuine NaN
-                    return (ms.ascent_grad, jnp.float32(jnp.nan),
-                            ms.staleness + 1)
+                    def reuse(_):
+                        # ascent_loss is a NaN SENTINEL here (no ascent
+                        # pass ran, there is no loss to report); the explicit
+                        # ascent_reused flag below is what disambiguates it
+                        # from a genuine NaN
+                        return (ms.ascent_grad, jnp.float32(jnp.nan),
+                                ms.staleness + 1)
 
-                refresh = (state.step % cfg.ascent_interval) == 0
-                a_new, loss_asc, staleness = jax.lax.cond(refresh, fresh,
-                                                          reuse, None)
-                reused = (~refresh).astype(jnp.float32)
+                    refresh = (state.step % cfg.ascent_interval) == 0
+                    a_new, loss_asc, staleness = jax.lax.cond(
+                        refresh, fresh, reuse, None)
+                    reused = (~refresh).astype(jnp.float32)
 
-            # --- ascent-state refresh. On the fused path the cosine metric
-            # and the carried norm come from ONE pass over (a_t, a_{t-1})
-            # (kernels.fused_dot_norms) instead of three per-leaf reductions;
-            # lossless only, since compression changes the stored gradient.
-            # With bucket-resident state both operands already ARE buffers
-            # (a_new differentiated through the params view, ascent_grad
-            # carried resident), so the refresh is buffer -> buffer.
-            resident = buckets.is_bucketed(state.params)
-            if ((resident or buckets.fused_path_enabled(cfg.fused_update))
-                    and cfg.compressor == "none"):
-                a32 = trees.tree_cast(a_new, jnp.float32)
-                layout = (state.params.layout if resident
-                          else buckets.bucket_layout(a32))
-                dot, sq_new, sq_old = buckets.bucketed_dot_norms(
-                    a32, ms.ascent_grad, layout=layout)
-                cos = dot / (jnp.sqrt(sq_new) * jnp.sqrt(sq_old) + 1e-12)
-                comp_state = ms.compression
-                new_ms = AsyncSamState(
-                    ascent_grad=a32,
-                    ascent_norm=jnp.sqrt(sq_new),
-                    have_ascent=jnp.ones((), jnp.bool_),
-                    staleness=staleness,
-                    compression=comp_state,
-                )
-            else:
-                cos = trees.tree_cosine_similarity(a_new, ms.ascent_grad)
-                a_lossy, comp_state = compressor.compress(a_new, ms.compression)
-                new_ms = AsyncSamState(
-                    ascent_grad=trees.tree_cast(a_lossy, jnp.float32),
-                    ascent_norm=trees.global_norm(a_lossy),
-                    have_ascent=jnp.ones((), jnp.bool_),
-                    staleness=staleness,
-                    compression=comp_state,
-                )
-            if cfg.guard_update:
-                # keep a non-finite ascent refresh out of the CARRIED state:
-                # a NaN a_t held across steps poisons every later perturbation
-                # (0 * NaN is still NaN), so the refresh is guarded by its own
-                # finiteness, independent of the descent verdict in _finish
-                ok_a = jnp.isfinite(new_ms.ascent_norm)
-                new_ms = jax.tree.map(lambda n, o: jnp.where(ok_a, n, o),
-                                      new_ms, ms)
+                # --- ascent-state refresh. On the fused path the cosine
+                # metric and the carried norm come from ONE pass over (a_t,
+                # a_{t-1}) (kernels.fused_dot_norms) instead of three per-leaf
+                # reductions; lossless only, since compression changes the
+                # stored gradient. With bucket-resident state both operands
+                # already ARE buffers (a_new differentiated through the params
+                # view, ascent_grad carried resident), so the refresh is
+                # buffer -> buffer.
+                resident = buckets.is_bucketed(state.params)
+                if ((resident
+                     or buckets.fused_path_enabled(cfg.fused_update))
+                        and cfg.compressor == "none"):
+                    a32 = trees.tree_cast(a_new, jnp.float32)
+                    layout = (state.params.layout if resident
+                              else buckets.bucket_layout(a32))
+                    dot, sq_new, sq_old = buckets.bucketed_dot_norms(
+                        a32, ms.ascent_grad, layout=layout)
+                    cos = dot / (jnp.sqrt(sq_new) * jnp.sqrt(sq_old)
+                                 + 1e-12)
+                    comp_state = ms.compression
+                    new_ms = AsyncSamState(
+                        ascent_grad=a32,
+                        ascent_norm=jnp.sqrt(sq_new),
+                        have_ascent=jnp.ones((), jnp.bool_),
+                        staleness=staleness,
+                        compression=comp_state,
+                    )
+                else:
+                    cos = trees.tree_cosine_similarity(a_new, ms.ascent_grad)
+                    a_lossy, comp_state = compressor.compress(
+                        a_new, ms.compression)
+                    new_ms = AsyncSamState(
+                        ascent_grad=trees.tree_cast(a_lossy, jnp.float32),
+                        ascent_norm=trees.global_norm(a_lossy),
+                        have_ascent=jnp.ones((), jnp.bool_),
+                        staleness=staleness,
+                        compression=comp_state,
+                    )
+                if cfg.guard_update:
+                    # keep a non-finite ascent refresh out of the CARRIED
+                    # state: a NaN a_t held across steps poisons every later
+                    # perturbation (0 * NaN is still NaN), so the refresh is
+                    # guarded by its own finiteness, independent of the
+                    # descent verdict in _finish
+                    ok_a = jnp.isfinite(new_ms.ascent_norm)
+                    new_ms = jax.tree.map(
+                        lambda n, o: jnp.where(ok_a, n, o), new_ms, ms)
             metrics = {"loss": loss, "ascent_loss": loss_asc,
                        "ascent_norm": new_ms.ascent_norm,
                        "ascent_cosine": cos,
                        "ascent_reused": reused,
                        "perturbed": ms.have_ascent.astype(jnp.float32),
                        **_m(aux)}
+            # _finish runs the optimizer tail under its own "update" scope
             return _finish(state, optimizer, grads, new_ms, metrics,
                            guard=cfg.guard_update)
 
@@ -178,9 +191,11 @@ def make_ascent_fn(loss_fn: LossFn) -> Callable:
     bucket-resident snapshot at the edge).
     """
     def ascent(params, batch, rng):
-        (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch, rng)
-        g = trees.tree_cast(g, jnp.float32)
-        return g, trees.global_norm(g), loss
+        with jax.named_scope("ascent"):
+            (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                params, batch, rng)
+            g = trees.tree_cast(g, jnp.float32)
+            return g, trees.global_norm(g), loss
 
     return ascent
 
@@ -200,10 +215,12 @@ def make_descent_fn(cfg: MethodConfig, loss_fn: LossFn,
     def descent(state: TrainState, batch, a: Pytree, a_norm: jax.Array,
                 have_a: jax.Array):
         batch, _ = split_batch(batch)
-        rho_eff = jnp.where(have_a, cfg.rho, 0.0)
-        w_hat = _perturb(state.params, a, rho_eff, grad_norm=a_norm,
-                         fused=cfg.fused_update)
-        (loss, aux), grads = vg(w_hat, batch, step_rng(state))
+        with jax.named_scope("perturb"):
+            rho_eff = jnp.where(have_a, cfg.rho, 0.0)
+            w_hat = _perturb(state.params, a, rho_eff, grad_norm=a_norm,
+                             fused=cfg.fused_update)
+        with jax.named_scope("descent"):
+            (loss, aux), grads = vg(w_hat, batch, step_rng(state))
         return _finish(state, optimizer, grads, state.method_state,
                        {"loss": loss, **_m(aux)}, guard=cfg.guard_update)
 

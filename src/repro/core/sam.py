@@ -28,7 +28,8 @@ def make_sgd(cfg: MethodConfig) -> Method:
         def step(state: TrainState, batch):
             batch, _ = split_batch(batch)
             rng = step_rng(state)
-            (loss, aux), grads = vg(state.params, batch, rng)
+            with jax.named_scope("descent"):
+                (loss, aux), grads = vg(state.params, batch, rng)
             return _finish(state, optimizer, grads, (), {"loss": loss, **_m(aux)},
                            guard=cfg.guard_update)
 
@@ -52,11 +53,14 @@ def make_sam(cfg: MethodConfig) -> Method:
                 ascent_batch = batch
             rng = step_rng(state)
             # --- gradient ascent (perturbation) ---
-            (loss_w, _), g_ascent = vg(state.params, ascent_batch, rng)
-            w_hat = _perturb(state.params, g_ascent, cfg.rho,
-                             fused=cfg.fused_update)
+            with jax.named_scope("ascent"):
+                (loss_w, _), g_ascent = vg(state.params, ascent_batch, rng)
+            with jax.named_scope("perturb"):
+                w_hat = _perturb(state.params, g_ascent, cfg.rho,
+                                 fused=cfg.fused_update)
             # --- gradient descent at the perturbed point ---
-            (loss, aux), grads = vg(w_hat, batch, rng)
+            with jax.named_scope("descent"):
+                (loss, aux), grads = vg(w_hat, batch, rng)
             metrics = {"loss": loss, "loss_at_w": loss_w,
                        "ascent_norm": trees.global_norm(g_ascent), **_m(aux)}
             return _finish(state, optimizer, grads, (), metrics,

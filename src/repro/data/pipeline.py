@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.data.synthetic import TokenTask
 from repro.models.config import ModelConfig
+from repro.obs import current_tracker
 
 
 @dataclasses.dataclass
@@ -131,7 +132,9 @@ class TokenPipeline:
         t.start()
         try:
             while True:
-                s, batch = q.get()
+                with current_tracker().span("data_next", lane="data",
+                                            ready=q.qsize()):
+                    s, batch = q.get()
                 self._step = s + 1
                 yield batch
         finally:

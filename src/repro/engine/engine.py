@@ -17,6 +17,8 @@ from __future__ import annotations
 import time
 from typing import Any, Iterable, Optional, Sequence
 
+import jax
+
 from repro.core import TrainState
 from repro.engine.api import FitReport, StepExecutor
 from repro.engine.callbacks import Callback, CheckpointCallback
@@ -54,15 +56,15 @@ class Engine:
         def step(state: TrainState, batch: dict):
             trk = current_tracker()
             t0 = time.perf_counter()
-            with trk.span("train_step", lane="descent",
-                          step=int(state.step)):
+            at = _read_step(trk, state)
+            with trk.span("train_step", lane="descent", step=at):
                 state, metrics = self.executor.step(state, batch)
             dt = time.perf_counter() - t0
-            trk.log({**scalar_metrics(metrics), "step_time_s": dt},
-                    step=int(state.step))
-            trk.histogram("step_time_s", dt)
-            for cb in self.callbacks:
-                cb.on_step(self, state, metrics, dt)
+            trk.log({**_read_metrics(trk, metrics), "step_time_s": dt},
+                    step=_read_step(trk, state))
+            with trk.span("callbacks", lane="descent"):
+                for cb in self.callbacks:
+                    cb.on_step(self, state, metrics, dt)
             return state, metrics
 
         return step
@@ -162,14 +164,20 @@ class Engine:
             t0 = time.time()
             history: list = []
             it = it if it is not None else iter(self.data)
+            trk = current_tracker()
             try:
-                while int(state.step) < steps:
-                    try:
-                        batch = next(it)
-                    except StopIteration:
-                        break
-                    state, metrics = wrapped(state, batch)
-                    history.append(scalar_metrics(metrics))
+                # one iteration per "step" span: draw, step, history, and
+                # the read of the step counter that the next test compares
+                at = _read_step(trk, state)
+                while at < steps:
+                    with trk.span("step", lane="descent", step_num=at):
+                        try:
+                            batch = next(it)
+                        except StopIteration:
+                            break
+                        state, metrics = wrapped(state, batch)
+                        history.append(_read_metrics(trk, metrics))
+                        at = _read_step(trk, state)
             finally:
                 if hasattr(it, "close"):
                     it.close()   # stop a prefetching pipeline's worker now
@@ -191,3 +199,18 @@ class Engine:
 
     def __exit__(self, *exc):
         self.close()
+
+
+def _read_step(trk: Tracker, state: TrainState) -> int:
+    """`state.step` on the host: one device-to-host read, in its span."""
+    with trk.span("readback", lane="descent", of="step", n=1):
+        return int(state.step)
+
+
+def _read_metrics(trk: Tracker, metrics: dict) -> dict:
+    """`scalar_metrics(metrics)` in a span counting the device scalars it
+    reads back."""
+    n = sum(isinstance(v, jax.Array) and v.ndim == 0
+            for v in metrics.values())
+    with trk.span("readback", lane="descent", of="metrics", n=n):
+        return scalar_metrics(metrics)

@@ -21,6 +21,7 @@ from repro.core import Method, MethodConfig, TrainState, init_train_state, make_
 from repro.core.api import LossFn
 from repro.core.async_sam import AsyncSamState
 from repro.engine.api import ensure_metric_contract
+from repro.obs import current_tracker
 from repro.optim import GradientTransform, configure_fused
 from repro.utils import buckets
 
@@ -262,10 +263,12 @@ class FusedExecutor:
     def step(self, state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         assert self._jitted is not None, "call init_state before step"
         assert not self._closed, "executor is closed"
-        with self._scope():
+        trk = current_tracker()
+        with trk.span("dispatch", lane="descent"), self._scope():
             state, metrics = self._jitted(state, batch)
         if self.block:
-            jax.block_until_ready(state.params)
+            with trk.span("device_wait", lane="descent"):
+                jax.block_until_ready(state.params)
         ms = state.method_state
         tau = (ms.staleness if isinstance(ms, AsyncSamState)
                else jnp.zeros((), jnp.int32))

@@ -132,6 +132,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_fwd",
         interpret=interpret,
     )(qt, kt, vt)
 

@@ -66,6 +66,7 @@ def fused_axpy(alpha, x_flat: jax.Array, y_flat: jax.Array, *,
         in_specs=[_SCAL, _VEC, _VEC],
         out_specs=_VEC,
         out_shape=jax.ShapeDtypeStruct(y.shape, y_flat.dtype),
+        name="bucket_axpy",
         interpret=interpret,
     )(alpha, x, y)
     return out[:n]
@@ -97,6 +98,7 @@ def fused_dot_norms(a_flat: jax.Array, b_flat: jax.Array, *,
         in_specs=[ROW_BLOCK, ROW_BLOCK],
         out_specs=[TILE_BLOCK, TILE_BLOCK, TILE_BLOCK],
         out_shape=[part, part, part],
+        name="bucket_dot_norms",
         interpret=interpret,
     )(as_rows(a), as_rows(b))
     return jnp.sum(dot), jnp.sum(aa), jnp.sum(bb)
@@ -128,6 +130,7 @@ def delta_amax(p_flat: jax.Array, s_flat: jax.Array, e_flat: jax.Array, *,
         in_specs=[ROW_BLOCK, ROW_BLOCK, ROW_BLOCK],
         out_specs=TILE_BLOCK,
         out_shape=partials_shape(n_chunks),
+        name="delta_amax",
         interpret=interpret,
     )(as_rows(p), as_rows(s), as_rows(e))
     return jnp.max(partials)
@@ -168,6 +171,7 @@ def delta_encode_i8(p_flat: jax.Array, s_flat: jax.Array, e_flat: jax.Array,
         out_shape=[jax.ShapeDtypeStruct(p.shape, jnp.int8),
                    jax.ShapeDtypeStruct(p.shape, jnp.float32),
                    jax.ShapeDtypeStruct(p.shape, jnp.float32)],
+        name="delta_quantize",
         interpret=interpret,
     )(scale, p, s, e)
     return q[:n], s_new[:n], e_new[:n]
@@ -220,6 +224,7 @@ def sgd_epilogue(w_flat: jax.Array, g_flat: jax.Array, m_flat, clip_scale, lr,
             out_specs=[_VEC, _VEC],
             out_shape=[jax.ShapeDtypeStruct(w.shape, w_flat.dtype),
                        jax.ShapeDtypeStruct(w.shape, jnp.float32)],
+            name="sgd_momentum_update",
             interpret=interpret,
         )(scal, w, g, m)
         return w_new[:n], m_new[:n]
@@ -229,6 +234,7 @@ def sgd_epilogue(w_flat: jax.Array, g_flat: jax.Array, m_flat, clip_scale, lr,
         in_specs=[_SCAL, _VEC, _VEC],
         out_specs=_VEC,
         out_shape=jax.ShapeDtypeStruct(w.shape, w_flat.dtype),
+        name="sgd_update",
         interpret=interpret,
     )(scal, w, g)
     return w_new[:n], None
@@ -279,6 +285,7 @@ def adamw_epilogue(w_flat: jax.Array, g_flat: jax.Array, mu_flat: jax.Array,
         out_shape=[jax.ShapeDtypeStruct(w.shape, w_flat.dtype),
                    jax.ShapeDtypeStruct(w.shape, jnp.float32),
                    jax.ShapeDtypeStruct(w.shape, jnp.float32)],
+        name="adamw_update",
         interpret=interpret,
     )(scal, w, g, mu, nu)
     return w_new[:n], mu_new[:n], nu_new[:n]

@@ -131,6 +131,7 @@ def mamba2_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="mamba_chunk_scan",
         interpret=interpret,
     )(xh, jnp.swapaxes(xh, -1, -2), dth, jnp.swapaxes(dth, -1, -2),
       a.astype(jnp.float32), bh, ch, d.astype(jnp.float32))

@@ -98,6 +98,7 @@ def rwkv6_chunked(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         scratch_shapes=[pltpu.VMEM((V, K), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="rwkv_chunk_scan",
         interpret=interpret,
     )(tile(r, K), tile(k, K), tile(v, V), tile(w, K), u.reshape(H, 1, K))
 

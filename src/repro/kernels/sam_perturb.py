@@ -71,6 +71,7 @@ def sq_norm(g_flat: jax.Array, *, interpret: bool = False) -> jax.Array:
         in_specs=[ROW_BLOCK],
         out_specs=TILE_BLOCK,
         out_shape=partials_shape(n_chunks),
+        name="bucket_sq_norm",
         interpret=interpret,
     )(as_rows(g))
     return jnp.sum(partials)
@@ -101,6 +102,7 @@ def sam_perturb(w_flat: jax.Array, g_flat: jax.Array, rho, sq_norm_val, *,
         ],
         out_specs=pl.BlockSpec((CHUNK,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct(w.shape, w_flat.dtype),
+        name="sam_perturb",
         interpret=interpret,
     )(scale.reshape(1), w, g)
     return out[:n]

@@ -42,13 +42,16 @@ def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     under pjit, where a gather would all-gather the full-vocab logits per
     device (observed 80+GB/device in the dry-run).
     """
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1)
-    onehot = (vocab_iota == jnp.maximum(labels, 0)[..., None])
-    picked = jnp.sum(jnp.where(onehot, lf, 0.0), axis=-1)
-    mask = (labels >= 0).astype(jnp.float32)
-    return jnp.sum((lse - picked) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    with jax.named_scope("cross_entropy"):
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape,
+                                              lf.ndim - 1)
+        onehot = (vocab_iota == jnp.maximum(labels, 0)[..., None])
+        picked = jnp.sum(jnp.where(onehot, lf, 0.0), axis=-1)
+        mask = (labels >= 0).astype(jnp.float32)
+        return (jnp.sum((lse - picked) * mask)
+                / jnp.maximum(jnp.sum(mask), 1.0))
 
 
 def build_model(cfg: ModelConfig) -> ModelBundle:

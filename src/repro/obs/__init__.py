@@ -7,7 +7,9 @@ See `repro.obs.tracker` for the architecture. The short version:
 
 and every layer below — executors, ascent lanes, the remote client, the
 ascent pool's workers, the elastic resize path — reports spans and metrics
-through `current_tracker()` for the duration of the fit.
+through `current_tracker()` for the duration of the fit. Every span, with
+or without a tracker installed, is also a `jax.profiler` annotation named
+`repro.<name>`, so a profiler trace shows it on the device's clock.
 """
 from repro.obs.registry import (ENGINE_METRIC_KEYS,
                                 ENGINE_OPTIONAL_METRIC_KEYS, METRIC_KEYS,

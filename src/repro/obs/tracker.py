@@ -1,4 +1,4 @@
-"""Tracker — one observability funnel for scalars, counters, and spans.
+"""Tracker — one observability funnel for scalars and spans.
 
 Levanter's tracker idiom (a process-global "current tracker" every layer
 logs through) adapted to the two-lane AsyncSAM runtime: the Engine installs
@@ -18,7 +18,13 @@ A `Tracker` fans out to composable sinks:
     TraceEventSink  Chrome/Perfetto trace-event JSON with one named track
                     per lane (repro.obs.trace)
 
-Span timing uses `time.perf_counter()` everywhere (`trace_now`), so spans
+Every `Tracker.span`, on the null tracker too, is also a
+`jax.profiler.TraceAnnotation` named `repro.<name>` with the span's plain
+args as stats: under a `jax.profiler` session it lands in the profiler's
+trace, where host spans and device operations share one clock. With no
+session active the annotation costs about a microsecond.
+
+Sink timing uses `time.perf_counter()` everywhere (`trace_now`), so spans
 recorded on different threads of one process share a clock and render with
 true overlap in a trace viewer — the whole point: perturbation-hiding is
 visible as ascent-lane spans literally under the descent lane's.
@@ -31,6 +37,8 @@ import pathlib
 import threading
 import time
 from typing import Any, Iterator, Optional, Sequence, Union
+
+import jax
 
 from repro.obs.registry import (ENGINE_OPTIONAL_METRIC_KEYS, validate_keys)
 
@@ -176,17 +184,15 @@ class JsonlSink(Sink):
 
 
 class Tracker:
-    """Fan-out facade over sinks, plus process-local counters/histograms.
+    """Fan-out facade over sinks.
 
-    With no sinks it is the null tracker: every call is a cheap no-op, which
-    is what uninstrumented runs pay.
+    With no sinks it is the null tracker: `log`, `event` and `span_at` are
+    no-ops and a span is only its profiler annotation, which is what
+    uninstrumented runs pay.
     """
 
     def __init__(self, sinks: Sequence[Sink] = ()):
         self.sinks = list(sinks)
-        self._lock = threading.Lock()
-        self._counters: dict = {}
-        self._hists: dict = {}
 
     # --- scalars ------------------------------------------------------------
     def log(self, metrics: dict, *, step: int) -> None:
@@ -194,52 +200,41 @@ class Tracker:
         for sink in self.sinks:
             sink.log(metrics, step=step)
 
-    # --- counters / histograms ---------------------------------------------
-    def count(self, name: str, n: int = 1) -> None:
-        with self._lock:
-            self._counters[name] = self._counters.get(name, 0) + n
-
-    def histogram(self, name: str, value: float) -> None:
-        with self._lock:
-            self._hists.setdefault(name, []).append(float(value))
-
-    @property
-    def counters(self) -> dict:
-        with self._lock:
-            return dict(self._counters)
-
-    def summary(self) -> dict:
-        """Counters plus per-histogram {count,min,max,mean,p50,p95}."""
-        with self._lock:
-            hists = {}
-            for name, vals in self._hists.items():
-                s = sorted(vals)
-                n = len(s)
-                hists[name] = {
-                    "count": n, "min": s[0], "max": s[-1],
-                    "mean": sum(s) / n,
-                    "p50": s[int(0.50 * (n - 1))],
-                    "p95": s[int(0.95 * (n - 1))],
-                }
-            return {"counters": dict(self._counters), "histograms": hists}
-
     # --- spans / events -----------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, *, lane: str = "main",
-             **args: Any) -> Iterator[None]:
+             step_num: Optional[int] = None, **args: Any) -> Iterator[None]:
         """`with tracker.span("ascent_exchange", lane=..., tau=...):` —
         times the body and dispatches one Span to every sink on exit (also
-        on exception, so a failing step still shows its cost)."""
+        on exception, so a failing step still shows its cost).
+
+        The body runs inside a profiler annotation `repro.<name>` whose stats
+        are the args of plain type (str, int, float, bool; the others are
+        left out, so the annotation never reads a device value back). With
+        `step_num` it is a `StepTraceAnnotation`, the profiler's mark of one
+        training step, and the sinks see `step_num` among the args.
+        """
+        stats = {k: v for k, v in args.items()
+                 if isinstance(v, (str, int, float))}
+        if step_num is None:
+            note = jax.profiler.TraceAnnotation("repro." + name, **stats)
+        else:
+            args["step_num"] = step_num
+            note = jax.profiler.StepTraceAnnotation(
+                "repro." + name, step_num=step_num, **stats)
         t0 = trace_now()
         try:
-            yield
+            with note:
+                yield
         finally:
             self.span_at(name, lane=lane, t0=t0, t1=trace_now(), **args)
 
     def span_at(self, name: str, *, lane: str, t0: float, t1: float,
                 **args: Any) -> None:
         """Record a span whose endpoints were measured elsewhere (e.g. the
-        submit→harvest window of an asynchronous exchange)."""
+        submit→harvest window of an asynchronous exchange). Sinks only: the
+        profiler takes annotations as they happen and cannot be handed one
+        afterwards, so such a span is not in its trace."""
         if not self.sinks:
             return
         span = Span(name, lane, t0, t1, args)

@@ -120,7 +120,7 @@ def test_lint_script_passes_on_tree():
 
 
 # ---------------------------------------------------------------------------
-# tracker: global install, counters/histograms, spans
+# tracker: global install, spans, and their profiler annotations
 # ---------------------------------------------------------------------------
 
 def test_use_tracker_scoped_install_and_null_default():
@@ -130,19 +130,6 @@ def test_use_tracker_scoped_install_and_null_default():
     with use_tracker(trk) as active:
         assert current_tracker() is trk is active
     assert current_tracker() is base
-
-
-def test_tracker_counters_and_histogram_summary():
-    trk = Tracker()
-    for _ in range(3):
-        trk.count("harvests")
-    for v in (1.0, 2.0, 3.0, 4.0):
-        trk.histogram("step_time_s", v)
-    s = trk.summary()
-    assert s["counters"] == {"harvests": 3}
-    h = s["histograms"]["step_time_s"]
-    assert (h["count"], h["min"], h["max"]) == (4, 1.0, 4.0)
-    assert h["p50"] == 2.0 and h["p95"] == 3.0
 
 
 def test_span_records_lane_args_and_survives_exceptions():
@@ -160,6 +147,145 @@ def test_span_records_lane_args_and_survives_exceptions():
     assert sink.spans_on("ascent")[0].args == {"gen": 3}
     assert sink.spans[2].duration_s == pytest.approx(0.5)
     assert sink.spans[0].args["step"] == 7
+
+
+def _profiled(body) -> list:
+    """Run `body` under a jax.profiler session; the host events named
+    "repro.*" it recorded, as (name, stats, line index, start, end)."""
+    import glob
+    import tempfile
+    from jax.profiler import ProfileData
+    with tempfile.TemporaryDirectory() as tmp:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(tmp, profiler_options=options)
+        try:
+            body()
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(f"{tmp}/plugins/profile/*/*.xplane.pb")
+        data = ProfileData.from_file(path)
+    return [(e.name, dict(e.stats), i, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in data.planes if plane.name == "/host:CPU"
+            for i, line in enumerate(plane.lines) for e in line.events
+            if e.name.startswith("repro.")]
+
+
+@pytest.mark.parametrize("sinks", [0, 1], ids=["null", "memory"])
+def test_span_lands_in_the_profiler_trace(sinks):
+    sink = MemorySink()
+    trk = Tracker([sink] * sinks)
+
+    def body():
+        with trk.span("ascent_rpc", lane="descent", wire_bytes=64, gen=3,
+                      kind="job", tau=0.5, ok=True):
+            pass
+
+    events = _profiled(body)
+    assert [(n, st) for n, st, *_ in events] == [
+        ("repro.ascent_rpc", {"wire_bytes": 64, "gen": 3, "kind": "job",
+                              "tau": 0.5, "ok": 1})]
+    assert len(sink.spans) == sinks
+    if sinks:
+        assert sink.spans[0].args["wire_bytes"] == 64
+
+
+def test_null_tracker_records_nothing_in_memory():
+    null = current_tracker()
+    assert null.sinks == []
+    with null.span("train_step", lane="descent", step=1):
+        pass
+    null.span_at("ascent_exchange", lane="x", t0=0.0, t1=1.0)
+    null.event("guard_skip", lane="guard")
+    assert vars(null) == {"sinks": []}
+
+
+def test_step_span_is_a_step_annotation():
+    sink = MemorySink()
+    trk = Tracker([sink])
+
+    def body():
+        with trk.span("step", lane="descent", step_num=7):
+            with trk.span("readback", lane="descent", of="step", n=1):
+                pass
+
+    (step, st, line, s0, s1), (read, rt, line2, r0, r1) = _profiled(body)
+    assert (step, read) == ("repro.step", "repro.readback")
+    assert st["step_num"] == 7 and "_r" in st      # the profiler's step mark
+    assert rt == {"of": "step", "n": 1}
+    assert line == line2 and s0 <= r0 <= r1 <= s1
+    assert [s.args for s in sink.spans] == [{"of": "step", "n": 1},
+                                            {"step_num": 7}]
+
+
+def test_span_annotation_reads_no_device_value():
+    class Unprintable:
+        def __str__(self):
+            raise AssertionError("an annotation stat was formatted")
+
+    sink = MemorySink()
+    trk = Tracker([sink])
+    value = jnp.ones(())
+
+    def body():
+        with trk.span("callbacks", lane="descent", loss=value,
+                      other=Unprintable(), n=2):
+            pass
+
+    [(_, stats, *_)] = _profiled(body)
+    assert stats == {"n": 2}
+    assert sink.spans[0].args["loss"] is value      # sinks get every arg
+
+
+def test_null_span_costs_microseconds_without_a_session():
+    import time
+    trk = current_tracker()
+    n = 2000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with trk.span("readback", lane="descent", of="step", n=1):
+            pass
+    assert (time.perf_counter() - t0) / n < 50e-6
+
+
+def test_fit_spans_the_loop_the_step_and_the_reads():
+    from repro.data import PipelineConfig, TokenPipeline
+    from repro.models import build_model
+    from repro.models.config import ModelConfig
+    cfg = ModelConfig(name="tiny", family="dense", n_layers=1, d_model=16,
+                      n_heads=2, n_kv_heads=2, d_ff=32, vocab_size=32,
+                      act="silu", norm="nonparam_ln", tie_embeddings=True,
+                      remat="none", compute_dtype="float32")
+    bundle = build_model(cfg)
+    sink = MemorySink()
+    with FusedExecutor(bundle.loss_fn, _mcfg(), optim.sgd(0.1),
+                       donate=False) as ex:
+        state = ex.init_state(bundle.init(jax.random.PRNGKey(0)),
+                              jax.random.PRNGKey(1))
+        pipe = TokenPipeline(cfg, PipelineConfig(global_batch=2, seq_len=8,
+                                                 ascent_fraction=0.5))
+        Engine(ex, pipe).fit(state, 3, tracker=Tracker([sink]))
+    names = [s.name for s in sink.spans]
+    for name in ("data_next", "train_step", "dispatch", "device_wait",
+                 "callbacks"):
+        assert names.count(name) == 3, name
+    assert [s.args["step_num"] for s in sink.spans if s.name == "step"] \
+        == [0, 1, 2]
+    reads = [s.args for s in sink.spans if s.name == "readback"]
+    # the first test of the step counter, then per step: the step for the
+    # train_step span, the metrics and the step for the log, the metrics
+    # for the history, the step for the loop's next test
+    assert len(reads) == 1 + 5 * 3
+    assert {r["of"] for r in reads} == {"step", "metrics"}
+    assert all(r["n"] == 1 for r in reads if r["of"] == "step")
+    assert all(r["n"] >= 4 for r in reads if r["of"] == "metrics")
+    # nested as the loop runs them: each train_step inside a step, and
+    # dispatch and device_wait inside the train_step
+    by = {n: [s for s in sink.spans if s.name == n] for n in set(names)}
+    for loop, tstep, disp, wait in zip(by["step"], by["train_step"],
+                                       by["dispatch"], by["device_wait"]):
+        assert loop.t0 <= tstep.t0 <= disp.t0 <= disp.t1 <= wait.t0 \
+            <= wait.t1 <= tstep.t1 <= loop.t1
 
 
 # ---------------------------------------------------------------------------
