@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import block_sizes, flash_attention
 from repro.kernels.fused_update import (adamw_epilogue, fused_axpy,
                                         fused_dot_norms, sgd_epilogue)
 from repro.kernels.mamba2_scan import mamba2_chunked
@@ -47,6 +47,57 @@ def test_flash_attention_pallas_vs_reference(b, s, h, kv, hd, dtype, causal, win
     expect = ref.mha_reference(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32), **_tol(dtype))
+
+
+# the tiles `block_sizes` chooses (no block_q / block_k given)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("b,s,h,kv,hd,hd_v,window", [
+    (1, 1024, 2, 2, 64, 64, None),    # two blocks a side: clamped k/v index
+    (1, 1024, 2, 2, 64, 64, 200),     # window not a multiple of the block
+    (1, 512, 2, 2, 64, 64, 64),       # window narrower than the block
+    (2, 1024, 4, 2, 64, 64, None),    # GQA
+    (1, 1024, 2, 2, 96, 96, None),    # phi-3 head dim
+    (1, 1024, 2, 1, 256, 256, None),  # gemma-2b head dim, MQA
+    (1, 1024, 2, 2, 192, 128, None),  # MLA: value head narrower than q/k
+], ids=["causal", "window200", "window64", "gqa", "hd96", "hd256", "mla"])
+def test_flash_attention_chosen_tiles_vs_reference(b, s, h, kv, hd, hd_v,
+                                                   window, dtype):
+    bq, bk = block_sizes(s, s, window)
+    # several blocks a side: fully masked steps, whose k/v index is clamped
+    assert s // bq >= 2 and s // bk >= 2 and s % bq == 0 and s % bk == 0
+    if window is not None:
+        assert bq <= max(window, 128) and bk <= max(window, 128)
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (b, s, h, hd), dtype)
+    k = jax.random.normal(ks[1], (b, s, kv, hd), dtype)
+    v = jax.random.normal(ks[2], (b, s, kv, hd_v), dtype)
+    out = flash_attention(q, k, v, causal=True, window=window, interpret=True)
+    expect = ref.mha_reference(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expect, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("sq,sk,window,want", [
+    (2048, 2048, None, (512, 512)),     # olmo-1b
+    (8192, 8192, None, (512, 512)),     # gemma-2b
+    (4096, 4096, None, (512, 512)),     # phi-3-vision
+    (2048, 2048, None, (512, 512)),     # whisper, train stress shape
+    (384, 1024, None, (128, 512)),      # cross-attention, sq != sk
+    (4096, 4096, 4096, (512, 512)),     # mixtral's window
+    (2048, 2048, 300, (256, 256)),      # a window narrower than 512
+    (2048, 2048, 64, (128, 128)),       # a window narrower than 128
+    (64, 64, None, (64, 64)),           # one short block
+    (1500, 1500, None, (128, 128)),     # whisper encoder: refused below
+], ids=["olmo", "gemma", "phi3", "whisper", "cross", "mixtral", "window300",
+        "window64", "short", "whisper_encoder"])
+def test_flash_attention_block_sizes(sq, sk, window, want):
+    assert block_sizes(sq, sk, window) == want
+
+
+def test_flash_attention_refuses_a_sequence_no_tile_divides():
+    q = jnp.zeros((1, 1500, 2, 64), jnp.bfloat16)
+    with pytest.raises(AssertionError):
+        flash_attention(q, q, q, causal=False, interpret=True)
 
 
 @pytest.mark.parametrize("s,kv_block", [(256, 64), (512, 128)])

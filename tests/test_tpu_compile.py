@@ -67,6 +67,10 @@ ATTENTION = {
     # GQA, head dim 64, sliding window
     "gqa_hd64_window": dict(q=(1, 2048, 8, 64), kv=(1, 2048, 2, 64),
                             window=1024),
+    # gemma-2b: 8 heads of 256 over one kv head, 8k context
+    "gemma_2b": dict(q=(1, 8192, 8, 256), kv=(1, 8192, 1, 256), window=None),
+    # phi-3-vision: 32 heads of 96
+    "phi3_hd96": dict(q=(1, 4096, 32, 96), kv=(1, 4096, 32, 96), window=None),
 }
 
 
