@@ -11,6 +11,10 @@ program with half of each descent batch left out inside the step (the mean
 taken over the rest). One JSON line per reading, then a summary: the
 largest sound reading and the smallest control and fault readings of each
 number.
+
+The reference, and the control, are the configuration's architecture
+(`bench/archs/<arch>.py`) placed on the cell's chips by
+`check.placement`, as in `run.py`.
 """
 import argparse
 import json
@@ -34,22 +38,17 @@ def program_numbers(cell, config, seed, **faults) -> dict:
     prog = system.ProgramSystem(cell, config, seed, **faults)
     r = prog.set_up()
     prog.close()
-    dims = system.model_dims(config)
-    ref = check.reference_readings(
-        dims, {**cell["train"], "method": cell["method"]}, seed, r["batches"])
-    got = {"loss": r["loss"], "g1": r["g1"],
-           "change": check.program_change(r["params3"], dims, seed),
-           "feed_rows": check.feed_rows(r["batches"], seed,
-                                        dims["vocab_size"], cell["batch"],
-                                        system.ascent_rows(cell))}
-    return check.numbers(got, ref)
+    del prog
+    return check.program_numbers(cell, config, seed, r)
 
 
 def control_numbers(cell, config, seed) -> dict:
     """The reference at float8 in the program's place, on the batches the
     program's pipeline would feed (the generator's streams 2k, 2k + 1)."""
-    from bench import check, generator, system
+    from bench import check, generator, load, system
+    arch = load.arch(config["arch"])
     dims = system.model_dims(config)
+    mesh = system.make_mesh(cell["mesh"])
     train = {**cell["train"], "method": cell["method"]}
     b, s, a = cell["batch"], cell["seq"], system.ascent_rows(cell)
     batches = []
@@ -61,8 +60,10 @@ def control_numbers(cell, config, seed) -> dict:
             batch["ascent"] = {"tokens": tok,
                                "labels": generator.labels_of(tok)}
         batches.append(batch)
-    got = check.reference_readings(dims, train, seed, batches, "fp8")
-    ref = check.reference_readings(dims, train, seed, batches)
+    params0 = check.seed_params(arch, dims, seed, mesh)
+    got = check.reference_readings(arch, dims, train, params0, batches, mesh,
+                                   "fp8")
+    ref = check.reference_readings(arch, dims, train, params0, batches, mesh)
     return check.numbers(got, ref)
 
 

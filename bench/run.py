@@ -6,7 +6,9 @@ Refuses to run without a TPU (or with fewer chips than the cell asks for):
 exit code 2 and no result. Otherwise it makes the weights and the traffic
 from --seed, builds the program's training path (`system.ProgramSystem`),
 drives its first three steps in set-up, measures `--seconds` of steady
-steps, and checks the first steps against the plain reference. With
+steps, and checks the first steps against the plain reference (the
+configuration's architecture, `bench/archs/<arch>.py`, placed on the cell's
+chips by `check.placement`). With
 `--trace 1` the window runs under the profiler and the per-layer metrics are
 reported; with `--trace 0` the end-to-end metrics. The last line of standard
 output is the JSON result; the last lines of standard error are the numbers
@@ -42,9 +44,10 @@ class Context:
     """What a per-layer metric's reader may read (see bench/metrics/)."""
 
     def __init__(self, cell, config, window, trace, peaks, peak_bytes):
-        from bench import system
+        from bench import load, system
         self.cell = cell
         self.config = config
+        self.arch = load.arch(config["arch"])
         self.dims = system.model_dims(config)
         self.chips = cell["chips"]
         self.rows = cell["batch"]
@@ -153,16 +156,7 @@ def run_cell(cell: dict, config: dict, seed: int, seconds: float,
                                              cell["name"])}
 
     t_check = time.perf_counter()
-    dims = system.model_dims(config)
-    ref = check.reference_readings(
-        dims, {**cell["train"], "method": cell["method"]}, seed,
-        readings["batches"])
-    got = {"loss": readings["loss"], "g1": readings["g1"],
-           "change": check.program_change(readings["params3"], dims, seed),
-           "feed_rows": check.feed_rows(
-               readings["batches"], seed, dims["vocab_size"], cell["batch"],
-               system.ascent_rows(cell))}
-    nums = check.numbers(got, ref)
+    nums = check.program_numbers(cell, config, seed, readings)
     correct, lines = check.verdict(nums, cell["limits"])
     out.update(correct=correct, metrics=metrics, device=device)
     out["checks"] = {k: {"value": nums.get(k), "limit": v}

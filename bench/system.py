@@ -2,7 +2,10 @@
 
 `ProgramSystem` builds what `repro.launch.train` builds: `TokenPipeline`
 (fed by this benchmark's `TokenSource`) -> `FusedExecutor` on the cell's
-("data", "model") mesh -> `Engine.fit`. Set-up drives that one engine through
+("data", "model") mesh -> `Engine.fit`. The weights are the configuration's
+architecture's (`bench/archs/<arch>.py`, by its "arch"), made from the seed
+and placed by the program's own sharding rules (`launch.sharding`), as a
+training job places them. Set-up drives that one engine through
 its first three steps (compiling on the first) and records, for the
 correctness check, each step's loss, each leaf's first gradient as AdamW
 received it (its first moment after one step over 1 - b1) and the parameters
@@ -21,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import generator, weights
+from bench import generator, load, weights
 
 CHECK_STEPS = 3
 
@@ -181,11 +184,12 @@ class ProgramSystem:
         self.marks: list[tuple[str, float]] = []
         mc = ModelConfig(**config["model"])
         self.mesh = make_mesh(cell["mesh"])
-        shapes = jax.eval_shape(lambda: weights.init_params(
+        arch = load.arch(config["arch"])
+        shapes = jax.eval_shape(lambda: arch.init_params(
             jax.random.PRNGKey(0), config["model"]))
         shardings = to_named(state_spec_tree(shapes, mc, self.mesh),
                              self.mesh)
-        params = weights.make_params(seed, config["model"], shardings)
+        params = weights.make_params(seed, arch, config["model"], shardings)
         jax.block_until_ready(params)
         self.marks.append(("weights made", time.perf_counter()))
         method = MethodConfig(name=cell["method"], rho=train["rho"],

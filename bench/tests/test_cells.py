@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import functools
 import json
+import math
 import os
 import subprocess
 import sys
@@ -20,6 +21,7 @@ from bench import calibrate, load, peaks, reference, run, system, weights
 
 BENCH = load.benchmark()
 ONE_CHIP = [w["name"] for w in BENCH["workloads"] if w["chips"] == 1]
+FOUR_CHIP = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
 # src/repro/configs/olmo_1b.py REDUCED, at a short sequence
 REDUCED = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
            "d_ff": 128, "vocab_size": 256, "remat": "none",
@@ -67,27 +69,112 @@ def test_rehearsal_traced():
     assert len(res["breakdown"]["idle_gaps"]) <= 10
 
 
-def test_rehearsal_on_four_virtual_devices():
-    """The harness's sharded path: the AsyncSAM cell on a (4, 1) data mesh
-    of virtual CPU devices, 8 rows and b' = 4 (one a device), per-leaf
-    state sharded by the program's rules, checked against the reference."""
-    code = f"""
-import json, sys, time
-sys.path[:0] = [{str(load.ROOT)!r}, {str(load.ROOT / 'src')!r}]
-import jax
-from bench.tests.test_cells import run_tiny
-out = run_tiny("olmo-1b-3l.async_sam", chips=4, mesh=[4, 1], batch=8)
-print(json.dumps({{"correct": out["result"]["correct"],
-                   "lines": out["lines"], "devices": jax.device_count()}}))
-"""
+def _on_four_devices(code: str) -> dict:
+    """Run `code` in a process that sees 4 virtual CPU devices; the JSON
+    object on its last line of output."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
+    head = (f"import json, sys\nsys.path[:0] = [{str(load.ROOT)!r}, "
+            f"{str(load.ROOT / 'src')!r}]\n")
+    proc = subprocess.run([sys.executable, "-c", head + code], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    got = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert got["devices"] == 4
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.fixture(scope="module")
+def four_chip_runs():
+    """Each four-chip cell rehearsed whole on a (4, 1) mesh of virtual CPU
+    devices at REDUCED widths, sound and with half of each descent batch
+    left out inside the step, in one process."""
+    return _on_four_devices(f"""
+import functools
+import jax
+from bench import calibrate, system
+from bench.tests.test_cells import run_tiny
+out = {{"devices": jax.device_count()}}
+for name in {FOUR_CHIP!r}:
+    half = functools.partial(system.ProgramSystem,
+                             alter_step=calibrate.half_batch)
+    out[name] = {{fault: {{"correct": r["result"]["correct"],
+                           "lines": r["lines"]}}
+                 for fault, r in (("none", run_tiny(name)),
+                                  ("half_batch", run_tiny(
+                                      name, system_cls=half)))}}
+print(json.dumps(out))
+""")
+
+
+@pytest.mark.parametrize("name", FOUR_CHIP)
+def test_rehearsal_on_four_virtual_devices(four_chip_runs, name):
+    """The harness's sharded path: the cell's per-leaf state sharded by the
+    program's rules, checked against the reference placed on the same
+    four devices."""
+    assert four_chip_runs["devices"] == 4
+    got = four_chip_runs[name]["none"]
     assert got["correct"], got["lines"]
+
+
+@pytest.mark.parametrize("name", FOUR_CHIP)
+def test_half_batch_on_four_virtual_devices_is_not_correct(four_chip_runs,
+                                                           name):
+    got = four_chip_runs[name]["half_batch"]
+    assert not got["correct"], got["lines"]
+
+
+def test_mesh_placed_reference_matches_one_device():
+    """The reference on a (4, 1) mesh reads what it reads on one device, to
+    within float32 reduction order, and no leaf of `check.SPLIT_LEAF`
+    elements or more sits whole on one device: not the seed's weights, not
+    the reference's state, not the batch's rows."""
+    got = _on_four_devices(f"""
+import jax
+import numpy as np
+from bench import check, generator, load, system
+arch = load.arch("olmo")
+# embed 4096 x 256 and the SwiGLU stacks 4 x 256 x 1024 are 2**20
+# elements; the attention stacks 4 x 256 x 256 are not
+dims = {{**{REDUCED!r}, "n_layers": 4, "d_model": 256, "d_ff": 1024,
+        "vocab_size": 4096, "norm_eps": 1e-6, "rope_theta": 1e4,
+        "tie_embeddings": True}}
+cell = load.workload({FOUR_CHIP[0]!r})
+train = {{**cell["train"], "method": cell["method"]}}
+batches = []
+for k in range(3):
+    tok = generator.rows(5, 4096, 8, 64, 2 * k)
+    asc = generator.rows(5, 4096, 4, 64, 2 * k + 1)
+    batches.append({{"tokens": tok, "labels": generator.labels_of(tok),
+                    "ascent": {{"tokens": asc,
+                               "labels": generator.labels_of(asc)}}}})
+out = {{}}
+for shape in ([1, 1], [4, 1]):
+    mesh = system.make_mesh(shape)
+    params0 = check.seed_params(arch, dims, 5, mesh)
+    out[str(shape)] = check.reference_readings(arch, dims, train, params0,
+                                               batches, mesh)
+    _, st_sh = check._reference_step(arch, dims, train, "fp32", mesh,
+                                     params0)
+    placed = [jax.tree.map(lambda x: x.sharding, params0), st_sh.params,
+              st_sh.mu, st_sh.nu, st_sh.ascent]
+    pairs = [(x, sh) for tree in placed
+             for x, sh in zip(jax.tree.leaves(params0), jax.tree.leaves(tree))
+             if x.size >= check.SPLIT_LEAF]
+    pairs += zip(jax.tree.leaves(batches[0]),
+                 jax.tree.leaves(check.row_placement(batches[0], mesh)))
+    out[str(shape) + " split"] = [(x.shape, sh.shard_shape(x.shape))
+                                  for x, sh in pairs]
+print(json.dumps(out))
+""")
+    one, four = got["[1, 1]"], got["[4, 1]"]
+    # float32 reduction order: the two read within 2.3e-07 of each other
+    for key in ("loss", "g1", "change"):
+        np.testing.assert_allclose(four[key], one[key], rtol=1e-6)
+    split = got["[4, 1] split"]
+    # 4 leaves of 2**20 in the weights and the state's 4 trees; 4 row arrays
+    assert len(split) == 4 * 5 + 4
+    for shape, shard in split:
+        assert 4 * math.prod(shard) == math.prod(shape), (shape, shard)
+    assert all(shard == shape for shape, shard in got["[1, 1] split"])
 
 
 def test_the_command_refuses_a_cpu():
@@ -132,10 +219,11 @@ class Control(system.ProgramSystem):
 
     def set_up(self):
         r = super().set_up()
+        arch = load.arch(self.config["arch"])
         dims = system.model_dims(self.config)
         train = {**self.cell["train"], "method": self.cell["method"]}
-        st = reference.init_state(weights.make_params(self.seed, dims))
-        step = jax.jit(reference.make_step(dims, train, "fp8"))
+        st = reference.init_state(weights.make_params(self.seed, arch, dims))
+        step = jax.jit(reference.make_step(arch.loss, dims, train, "fp8"))
         losses, g1 = [], []
         for k, batch in enumerate(r["batches"]):
             st, loss, g = step(st, jax.tree.map(jnp.asarray, batch))

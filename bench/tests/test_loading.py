@@ -76,6 +76,33 @@ def test_every_config_file_states_its_cut(entry):
     assert any(w["config"] == entry["name"] for w in BENCH["workloads"])
 
 
+ARCH_API = ("init_params", "loss", "matmul_params", "param_count",
+            "train_flops_per_token")
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_config_names_an_arch_that_resolves(entry):
+    arch = load.arch(load.config(entry["name"])["arch"])
+    assert all(callable(getattr(arch, f)) for f in ARCH_API)
+    assert load.arch(load.config(entry["name"])["arch"]) is arch
+
+
+def test_an_added_arch_file_is_found_with_no_other_edit(tmp_path):
+    (tmp_path / "archs").mkdir()
+    (tmp_path / "archs" / "toy.py").write_text(
+        "def init_params(key, dims):\n    return {}\n"
+        "def loss(params, batch, dims, precision='fp32'):\n    return 0.0\n"
+        "def matmul_params(dims):\n    return dims['width'] ** 2\n"
+        "def param_count(dims):\n    return dims['width'] ** 2\n"
+        "def train_flops_per_token(dims, seq):\n"
+        "    return 6 * matmul_params(dims)\n")
+    toy = load.arch("toy", tmp_path)
+    assert all(callable(getattr(toy, f)) for f in ARCH_API)
+    assert toy.train_flops_per_token({"width": 3}, 8) == 54
+    with pytest.raises(FileNotFoundError):
+        load.arch("absent", tmp_path)
+
+
 @pytest.mark.parametrize("entry", BENCH["per_layer"], ids=lambda m: m["name"])
 def test_every_metric_resolves_to_a_reader(entry):
     assert callable(load.reader(entry["name"]))

@@ -104,29 +104,31 @@ def test_kernel_readers_find_nothing_without_kernels():
 
 
 def test_olmo_parameter_counts_by_hand():
+    olmo = load.arch("olmo")
     dims = load.config("olmo-1b-3l")["model"]
     # per layer: q, k, v, o 4 * 2048^2; SwiGLU 3 * 2048 * 8192
     layer = 4 * 2048 * 2048 + 3 * 2048 * 8192
-    assert flops.layer_matmul_params(dims) == layer == 67_108_864
+    assert olmo.layer_matmul_params(dims) == layer == 67_108_864
     # tied embedding 50304 x 2048; the LayerNorms have no parameters
-    assert flops.param_count(dims) == 3 * layer + 50304 * 2048
-    assert flops.param_count(dims) == 304_349_184      # 0.304 B
+    assert olmo.param_count(dims) == 3 * layer + 50304 * 2048
+    assert olmo.param_count(dims) == 304_349_184      # 0.304 B
     whole = {**dims, "n_layers": 16}     # OLMo-1B as published
-    assert flops.param_count(whole) == 16 * layer + 50304 * 2048
-    assert flops.param_count(whole) == 1_176_764_416   # 1.18 B
-    assert flops.param_count({**dims, "tie_embeddings": False}) == (
+    assert olmo.param_count(whole) == 16 * layer + 50304 * 2048
+    assert olmo.param_count(whole) == 1_176_764_416   # 1.18 B
+    assert olmo.param_count({**dims, "tie_embeddings": False}) == (
         304_349_184 + 50304 * 2048)
 
 
 def test_step_flops_by_hand():
+    olmo = load.arch("olmo")
     dims = load.config("olmo-1b-3l")["model"]
     cell = load.workload("olmo-1b-3l.async_sam")
     per_token = 6 * 304_349_184 + 6 * 3 * 2048 * 2049
-    assert flops.train_flops_per_token(dims, 2048) == per_token
+    assert olmo.train_flops_per_token(dims, 2048) == per_token
     # descent 2 rows and ascent 1 row of 2048 tokens: about 11.7 TFLOP
     rows, asc = cell["batch"], system.ascent_rows(cell)
     assert (rows, asc) == (2, 1)
-    got = flops.step_flops(dims, 2048, rows, asc)
+    got = flops.step_flops(olmo, dims, 2048, rows, asc)
     assert got == 3 * 2048 * per_token
     assert 11.6e12 < got < 11.8e12
 
@@ -151,3 +153,72 @@ def test_peaks_refuse_an_unknown_device():
         peaks.peaks("TPU v4")
     with pytest.raises(KeyError):
         peaks.peaks("cpu")
+
+
+# --- collective_exposed_ms ------------------------------------------------
+
+AG_START = ('%all-gather-start.3 = (f32[4096,2048]{1,0}, f32[16384,2048]{1,0}) '
+            'all-gather-start(f32[4096,2048]{1,0} %p), channel_id=7, '
+            'replica_groups=[1,4]<=[4], dimensions={0}')
+AG_DONE = ('%all-gather-done.3 = f32[16384,2048]{1,0} all-gather-done('
+           '(f32[4096,2048]{1,0}, f32[16384,2048]{1,0}) %all-gather-start.3)')
+ALL_REDUCE = ('%all-reduce.18 = f32[2048,8192]{1,0} all-reduce(f32[2048,8192]'
+              '{1,0} %g), channel_id=9, replica_groups=[1,4]<=[4], '
+              'to_apply=%add')
+FUSION_OF_GATHERED = ('%fusion.7 = bf16[2,2048,2048]{2,1,0} fusion(f32[16384,'
+                      '2048]{1,0} %all-gather-done.3), kind=kLoop')
+
+
+def _exposed(devices, steps=1, window=(0, 10**9)):
+    class Ctx:
+        pass
+    ctx = Ctx()
+    ctx.trace = Trace(devices, [Op("bench.window", *window, "")])
+    ctx.steps = steps
+    return load.reader("collective_exposed_ms")(ctx)
+
+
+@pytest.mark.parametrize("name, want", [
+    (AG_START, "all-gather-start"), (AG_DONE, "all-gather-done"),
+    (ALL_REDUCE, "all-reduce"), (FUSION_OF_GATHERED, "fusion"),
+    (TPU_ATTN, "custom-call"), ("all-reduce.12", "all-reduce"),
+    ("reduce-scatter.3.1", "reduce-scatter"), ("dot_general.1", "dot_general"),
+])
+def test_collectives_are_matched_by_opcode(name, want):
+    opcode = load.reader("collective_exposed_ms").__globals__["opcode"]
+    assert opcode(name) == want
+
+
+def test_collective_time_under_compute_is_not_exposed():
+    # all-reduce 0-10 ms, a fusion inside it 4-7 ms: 7 ms exposed
+    ms = 1_000_000
+    ops = [Op(ALL_REDUCE, 0, 10 * ms, ""), Op(TPU_FUSION, 4 * ms, 7 * ms, "")]
+    assert _exposed({0: ops}) == pytest.approx(7.0)
+
+
+def test_an_async_pair_counts_its_own_events_not_the_transfer():
+    # start 0-1, compute 1-9 hides the transfer, done 9-12 waits on it
+    ms = 1_000_000
+    ops = [Op(AG_START, 0, 1 * ms, ""), Op(FUSION_OF_GATHERED, 1 * ms, 9 * ms,
+                                          ""), Op(AG_DONE, 9 * ms, 12 * ms, "")]
+    assert _exposed({0: ops}) == pytest.approx(4.0)
+    # with the device idle between them, the idle time is not counted
+    assert _exposed({0: [ops[0], ops[2]]}) == pytest.approx(4.0)
+
+
+def test_collective_time_is_per_step_on_the_busiest_chip():
+    ms = 1_000_000
+    chip0 = [Op(ALL_REDUCE, 0, 6 * ms, ""), Op(ALL_REDUCE, 20 * ms, 26 * ms, "")]
+    chip1 = [Op(ALL_REDUCE, 0, 10 * ms, ""), Op(ALL_REDUCE, 20 * ms, 30 * ms,
+                                                 "")]
+    chip2 = [Op(TPU_FUSION, 0, 40 * ms, "")]
+    # 20 ms on chip 1 over 2 steps; chip 2 runs no collective
+    assert _exposed({0: chip0, 1: chip1, 2: chip2}, steps=2) == (
+        pytest.approx(10.0))
+    # only what lies inside the window counts
+    assert _exposed({1: chip1}, window=(5 * ms, 25 * ms)) == pytest.approx(
+        10.0)
+
+
+def test_no_collective_reads_nothing():
+    assert _exposed({0: [Op(TPU_FUSION, 0, 10, "")]}) is None

@@ -9,6 +9,7 @@ recorded trace checks them.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import glob
 import os
 import re
@@ -178,13 +179,23 @@ def custom_calls(ops: Iterable[Op]) -> list:
     """
     out = []
     for o in ops:
-        m = _CALL.match(o.name)
-        if m:
-            out.append(CustomCall(o, _arrays(m.group(1)), _arrays(
-                re.sub(r"\{[^}]*\}", "", m.group(2)))))
+        types = _call_types(o.name)
+        if types:
+            out.append(CustomCall(o, *types))
     return out
 
 
+@functools.lru_cache(maxsize=2**16)
+def _call_types(name: str) -> Optional[tuple]:
+    """(results, operands) of a Pallas kernel's instruction, else None;
+    one parse per instruction, which a trace repeats every step."""
+    m = "tpu_custom_call" in name and _CALL.match(name)
+    if not m:
+        return None
+    return _arrays(m.group(1)), _arrays(re.sub(r"\{[^}]*\}", "", m.group(2)))
+
+
+@functools.lru_cache(maxsize=2**16)
 def label(name: str) -> str:
     """A short name for an operation: its HLO instruction's name without
     the numeric suffix, and its result type ("fusion f32[2,2048]")."""
